@@ -394,7 +394,8 @@ def main(argv=None):
               f"p99 {rec['p99_ms']:.1f} ms | "
               f"{rec['throughput_rps']:.0f} rps, "
               f"occupancy {rec['occupancy_mean']:.2f}, "
-              f"shed {rec['shed_rate']:.3f}, panels {rec['panels']}")
+              f"shed {rec['shed_rate']:.3f}, panels {rec['panels']}, "
+              f"serve traces {guard.engine.counters['serve_traces']}")
         return _exit_code(args, rec["degraded"])
 
     # int8 scoring is approximate: measure its live quality against the

@@ -1,3 +1,37 @@
+"""The serving stack: ``MicrobatchServer`` → ``GuardedEngine`` →
+``RetrievalEngine`` → kernels.
+
+Each layer records its steps as ``jax.profiler.TraceAnnotation`` spans,
+host events on the profiler's clock beside the device's ops.  With no
+trace recording a span costs about a microsecond.
+
+=========================  ==========  ================================
+span                       thread      covers (stats)
+=========================  ==========  ================================
+``serve.batcher.submit``   caller      validation and enqueue of one
+                                       request (``request``: its number)
+``serve.batcher.coalesce`` dispatcher  waiting for arrivals until the
+                                       panel is drained (``rows``)
+``serve.batcher.panel``    dispatcher  one whole panel (``panel``,
+                                       ``rows``, ``bucket``, ``requests``:
+                                       the request numbers it carries)
+``serve.batcher.assemble`` dispatcher  concatenation and zero padding
+``serve.guard.admit``      dispatcher  validation and the host-side
+                                       finiteness check (``rows``)
+``serve.engine.dispatch``  dispatcher  validation, padding and the serve
+                                       jit's call, not its device work
+                                       (``rows``, ``n``)
+``serve.engine.trace``     dispatcher  one trace of the serve program
+                                       (``rows``, ``n``)
+``serve.batcher.wait``     dispatcher  blocking on the panel's result
+``serve.batcher.resolve``  dispatcher  per-request slicing and
+                                       ``set_result`` with its callbacks
+                                       (``requests``: how many)
+=========================  ==========  ================================
+
+``RetrievalEngine.counters["serve_traces"]`` counts the traces of the
+serve program, one per panel shape and top-n.
+"""
 from repro.serving.batcher import DEFAULT_BUCKETS, MicrobatchServer
 from repro.serving.config import (
     PRECISIONS,

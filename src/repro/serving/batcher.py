@@ -31,16 +31,21 @@ Semantics, in order of what matters:
   normal path and still carries its ``ServingStatus``.
 * **The unified response.**  Every request resolves to the same
   ``RetrievalResponse`` the engine and guard return, with ``queue_us``
-  (submit → dispatch) and ``compute_us`` (the blocked panel round-trip,
-  shared by the panel's requests) filled in and the underlying layer's
+  (submit → dispatch) filled in and the underlying layer's
   ``ServingStatus`` passed through — batching is invisible to the
   response surface.
+* **Spans.**  Each step is a ``serve.batcher.*`` profiler span (see
+  ``repro.serving``): ``submit`` on the caller's thread, and on the
+  dispatcher's ``coalesce``, then ``panel`` around ``assemble``, the
+  engine call, ``wait`` and ``resolve``.  Admitted requests and panels
+  are numbered, so a panel's span names the requests it carries.
 
 Requests with different ``n`` never share a panel (the top-n width is a
 compile-time constant of the serve jit); the queue stays FIFO per ``n``.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -50,6 +55,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.errors import EngineConfigError, InvalidQueryError, QueueFullError
 from repro.kernels.sparse_dot.kernel import BLOCK_Q
@@ -61,7 +67,7 @@ DEFAULT_BUCKETS = (BLOCK_Q, 2 * BLOCK_Q, 4 * BLOCK_Q, 8 * BLOCK_Q)
 class _Request:
     """One queued submission: rows + bookkeeping + the caller's future."""
 
-    __slots__ = ("x", "n", "rows", "squeeze", "t_submit", "future")
+    __slots__ = ("x", "n", "rows", "squeeze", "t_submit", "future", "seq")
 
     def __init__(self, x, n: int, rows: int, squeeze: bool):
         self.x = x
@@ -70,6 +76,7 @@ class _Request:
         self.squeeze = squeeze
         self.t_submit = time.monotonic()
         self.future: Future = Future()
+        self.seq = -1   # numbered at admission
 
 
 class MicrobatchServer:
@@ -128,6 +135,8 @@ class MicrobatchServer:
             "padded_rows": 0, "occupancy_sum": 0.0,
             "panels_by_bucket": {b: 0 for b in buckets},
         }
+        self._request_seq = itertools.count()
+        self._panel_seq = itertools.count()
         self._dispatcher = threading.Thread(
             target=self._loop, name="microbatch-dispatcher", daemon=True
         )
@@ -158,6 +167,13 @@ class MicrobatchServer:
         caller), and ``InvalidQueryError`` for malformed queries, so bad
         or shed requests never occupy panel slots.
         """
+        with TraceAnnotation("serve.batcher.submit") as span:
+            req = self._enqueue(x, n)
+            span.set_metadata(request=req.seq)
+        return req.future
+
+    def _enqueue(self, x, n: int) -> _Request:
+        """Validate one submission, then queue and number it (or shed)."""
         x = jnp.asarray(x) if isinstance(x, (list, np.ndarray)) else x
         if not hasattr(x, "ndim") or x.ndim not in (1, 2):
             raise InvalidQueryError(
@@ -188,12 +204,13 @@ class MicrobatchServer:
                     queued_rows=self._queued_rows,
                     max_queue_rows=self.max_queue_rows,
                 )
+            req.seq = next(self._request_seq)
             self._queue.append(req)
             self._queued_rows += rows
             self._stats["requests"] += 1
             self._stats["rows"] += rows
             self._cond.notify_all()
-        return req.future
+        return req
 
     def serve(self, x, n: int, timeout: Optional[float] = None
               ) -> RetrievalResponse:
@@ -245,7 +262,8 @@ class MicrobatchServer:
 
     def _loop(self) -> None:
         while True:
-            with self._cond:
+            with TraceAnnotation("serve.batcher.coalesce") as span, \
+                    self._cond:
                 while not self._queue and not self._closed:
                     self._cond.wait()
                 if not self._queue and self._closed:
@@ -261,6 +279,7 @@ class MicrobatchServer:
                         break
                     self._cond.wait(timeout=remaining)
                 batch = self._drain(head.n)
+                span.set_metadata(rows=sum(r.rows for r in batch))
             if batch:
                 self._dispatch(batch)
 
@@ -269,37 +288,42 @@ class MicrobatchServer:
         t_dispatch = time.monotonic()
         rows = sum(r.rows for r in batch)
         bucket = next(b for b in self.buckets if b >= rows)
-        try:
-            panel = jnp.concatenate([r.x for r in batch], axis=0)
-            if bucket > rows:
-                # zero padding rows: scored and discarded — they can
-                # never appear in any request's slice below
-                panel = jnp.concatenate(
-                    [panel, jnp.zeros((bucket - rows, panel.shape[1]),
-                                      dtype=panel.dtype)], axis=0
-                )
-            resp = self.engine.retrieve_dense(panel, batch[0].n)
-            jax.block_until_ready(resp.ids)
-        except BaseException as err:  # noqa: BLE001 — the caller's error
-            for r in batch:
-                r.future.set_exception(err)
-            return
-        t_done = time.monotonic()
-        with self._cond:
-            self._stats["panels"] += 1
-            self._stats["panels_by_bucket"][bucket] += 1
-            self._stats["padded_rows"] += bucket - rows
-            self._stats["occupancy_sum"] += rows / bucket
-        compute_us = (t_done - t_dispatch) * 1e6
-        off = 0
-        for r in batch:
-            s = resp.scores[off:off + r.rows]
-            i = resp.ids[off:off + r.rows]
-            off += r.rows
-            if r.squeeze:
-                s, i = s[0], i[0]
-            r.future.set_result(RetrievalResponse(
-                scores=s, ids=i, status=resp.status,
-                queue_us=(t_dispatch - r.t_submit) * 1e6,
-                compute_us=compute_us,
-            ))
+        with TraceAnnotation("serve.batcher.panel", panel=next(
+                self._panel_seq), rows=rows, bucket=bucket) as span:
+            if span.is_enabled():   # the list is built only when traced
+                span.set_metadata(
+                    requests=" ".join(str(r.seq) for r in batch))
+            try:
+                with TraceAnnotation("serve.batcher.assemble"):
+                    panel = jnp.concatenate([r.x for r in batch], axis=0)
+                    if bucket > rows:
+                        # zero padding rows: scored and discarded — they can
+                        # never appear in any request's slice below
+                        panel = jnp.concatenate(
+                            [panel, jnp.zeros((bucket - rows, panel.shape[1]),
+                                              dtype=panel.dtype)], axis=0
+                        )
+                resp = self.engine.retrieve_dense(panel, batch[0].n)
+                with TraceAnnotation("serve.batcher.wait"):
+                    jax.block_until_ready(resp.ids)
+            except BaseException as err:  # noqa: BLE001 — the caller's error
+                for r in batch:
+                    r.future.set_exception(err)
+                return
+            with self._cond:
+                self._stats["panels"] += 1
+                self._stats["panels_by_bucket"][bucket] += 1
+                self._stats["padded_rows"] += bucket - rows
+                self._stats["occupancy_sum"] += rows / bucket
+            with TraceAnnotation("serve.batcher.resolve", requests=len(batch)):
+                off = 0
+                for r in batch:
+                    s = resp.scores[off:off + r.rows]
+                    i = resp.ids[off:off + r.rows]
+                    off += r.rows
+                    if r.squeeze:
+                        s, i = s[0], i[0]
+                    r.future.set_result(RetrievalResponse(
+                        scores=s, ids=i, status=resp.status,
+                        queue_us=(t_dispatch - r.t_submit) * 1e6,
+                    ))

@@ -40,7 +40,6 @@ previous generation broadcast.
 from __future__ import annotations
 
 import copy
-import time
 import warnings
 from collections.abc import Mapping
 from typing import NamedTuple, Optional
@@ -48,6 +47,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from repro import compat
@@ -493,6 +493,7 @@ class RetrievalEngine:
         self.inverted_cap = cfg.inverted_cap
         self._inv_norms = mode_inv_norms(index, cfg.mode)
         self._serve_cache: dict[int, callable] = {}
+        self.counters = {"serve_traces": 0}
         self._placed = None  # mesh engines: serve args placed on the mesh
         self.inverted = None
         if cfg.stage == "two_stage":
@@ -598,9 +599,13 @@ class RetrievalEngine:
             _, rebuild = self._serve_leaves()
 
             def _serve(arrays, xb):
-                eng = copy.copy(self)
-                eng.index, eng._inv_norms, eng.params = rebuild(arrays)
-                return eng.retrieve_codes(eng.encode_queries(xb), n)
+                # runs only while JAX traces: once per panel shape
+                self.counters["serve_traces"] += 1
+                with TraceAnnotation("serve.engine.trace",
+                                     rows=xb.shape[0], n=n):
+                    eng = copy.copy(self)
+                    eng.index, eng._inv_norms, eng.params = rebuild(arrays)
+                    return eng.retrieve_codes(eng.encode_queries(xb), n)
 
             fn = self._serve_cache[n] = jax.jit(_serve)
         return fn
@@ -672,57 +677,57 @@ class RetrievalEngine:
         exactly the panels the tuple-era API returned.  The stamped
         ``ServingStatus`` is the healthy configured path (step 0, not
         degraded) — the guard layer replaces it with what actually
-        happened when serving degrades.  ``compute_us`` records host
-        dispatch time; device completion stays the caller's
-        ``block_until_ready``, as before.
+        happened when serving degrades.  The call returns once the work
+        is dispatched; device completion stays the caller's
+        ``block_until_ready``.  It is the ``serve.engine.dispatch`` span.
         """
-        t0 = time.monotonic()
-        d = None if self.params is None else self.params["w_enc"].shape[0]
-        validate_dense_query(x, d=d)
-        validate_topn(
-            n,
-            self.index.codes.n if self.segments is None
-            else self.segments.n_rows,
-        )
-        squeeze = x.ndim == 1
-        xb = x[None] if squeeze else x
-        # Shape-stable serve path: every panel the jit sees is padded to
-        # a BLOCK_Q multiple with zero rows (scored and sliced off), so
-        # a lone request and a coalesced microbatch panel of the same
-        # bucket compile and compute IDENTICALLY — the bit-identity the
-        # batcher promises is structural, not an XLA accident — and
-        # per-request traffic of varied widths retraces once per bucket,
-        # not once per width.
-        rows = int(xb.shape[0])
-        pad = (-rows) % BLOCK_Q
-        if pad:
-            xb = jnp.concatenate(
-                [xb, jnp.zeros((pad, xb.shape[1]), dtype=xb.dtype)],
-                axis=0,
+        with TraceAnnotation("serve.engine.dispatch") as span:
+            d = None if self.params is None else self.params["w_enc"].shape[0]
+            validate_dense_query(x, d=d)
+            validate_topn(
+                n,
+                self.index.codes.n if self.segments is None
+                else self.segments.n_rows,
             )
-        if self.segments is not None or self.stage == "two_stage":
-            # segment content mutates between requests, and two-stage
-            # runs host work between its two jits — neither request can
-            # be one monolithic jit (segments: arrays would bake in as
-            # constants; the per-segment retrieves are module-level jits
-            # keyed on segment array SHAPES, so shape-preserving
-            # mutations recompile nothing and ``apply_update`` never
-            # invalidates).  The encode is its own cached jit.
-            fn = self._serve_cache.get("encode")
-            if fn is None:
-                fn = jax.jit(lambda xb: self.encode_queries(xb))
-                self._serve_cache["encode"] = fn
-            codes = fn(xb)
-            scores, ids = self.retrieve_codes(codes, n)
-        else:
-            scores, ids = self._serve_fn(n)(self._serve_args(), xb)
-        if pad:
-            scores, ids = scores[:rows], ids[:rows]
-        if squeeze:
-            scores, ids = scores[0], ids[0]
-        return RetrievalResponse(
-            scores=scores, ids=ids,
-            status=ServingStatus(path=path_name(self)),
-            queue_us=0.0,
-            compute_us=(time.monotonic() - t0) * 1e6,
-        )
+            squeeze = x.ndim == 1
+            xb = x[None] if squeeze else x
+            span.set_metadata(rows=xb.shape[0], n=n)
+            # Shape-stable serve path: every panel the jit sees is padded to
+            # a BLOCK_Q multiple with zero rows (scored and sliced off), so
+            # a lone request and a coalesced microbatch panel of the same
+            # bucket compile and compute IDENTICALLY — the bit-identity the
+            # batcher promises is structural, not an XLA accident — and
+            # per-request traffic of varied widths retraces once per bucket,
+            # not once per width.
+            rows = int(xb.shape[0])
+            pad = (-rows) % BLOCK_Q
+            if pad:
+                xb = jnp.concatenate(
+                    [xb, jnp.zeros((pad, xb.shape[1]), dtype=xb.dtype)],
+                    axis=0,
+                )
+            if self.segments is not None or self.stage == "two_stage":
+                # segment content mutates between requests, and two-stage
+                # runs host work between its two jits — neither request can
+                # be one monolithic jit (segments: arrays would bake in as
+                # constants; the per-segment retrieves are module-level jits
+                # keyed on segment array SHAPES, so shape-preserving
+                # mutations recompile nothing and ``apply_update`` never
+                # invalidates).  The encode is its own cached jit.
+                fn = self._serve_cache.get("encode")
+                if fn is None:
+                    fn = jax.jit(lambda xb: self.encode_queries(xb))
+                    self._serve_cache["encode"] = fn
+                codes = fn(xb)
+                scores, ids = self.retrieve_codes(codes, n)
+            else:
+                scores, ids = self._serve_fn(n)(self._serve_args(), xb)
+            if pad:
+                scores, ids = scores[:rows], ids[:rows]
+            if squeeze:
+                scores, ids = scores[0], ids[0]
+            return RetrievalResponse(
+                scores=scores, ids=ids,
+                status=ServingStatus(path=path_name(self)),
+                queue_us=0.0,
+            )

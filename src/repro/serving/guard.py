@@ -48,6 +48,7 @@ from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import sae
 from repro.core.quantized_codes import QuantizedCodes
@@ -635,18 +636,19 @@ class GuardedEngine:
         so.  Only when every rung fails does ``DegradationExhaustedError``
         surface, chaining each rung's reason.
         """
-        t0 = time.monotonic()
         deadline = Deadline(self.deadline_ms if deadline_ms is None
                             else deadline_ms)
         self.counters["requests"] += 1
         try:
-            seg = getattr(self.engine, "segments", None)
-            n = validate_topn(n, self.engine.index.codes.n if seg is None
-                              else seg.n_rows)
-            d = (None if self.engine.params is None
-                 else self.engine.params["w_enc"].shape[0])
-            validate_dense_query(x, d=d)
-            x, sanitized = self._admit_values(x)
+            with TraceAnnotation("serve.guard.admit") as span:
+                seg = getattr(self.engine, "segments", None)
+                n = validate_topn(n, self.engine.index.codes.n
+                                  if seg is None else seg.n_rows)
+                d = (None if self.engine.params is None
+                     else self.engine.params["w_enc"].shape[0])
+                validate_dense_query(x, d=d)
+                span.set_metadata(rows=1 if x.ndim == 1 else x.shape[0])
+                x, sanitized = self._admit_values(x)
         except InvalidQueryError:
             self.counters["rejected"] += 1
             raise
@@ -710,9 +712,7 @@ class GuardedEngine:
                 deadline_exceeded=deadline.expired,
             )
             return RetrievalResponse(
-                scores=scores, ids=ids, status=status,
-                queue_us=0.0,
-                compute_us=(time.monotonic() - t0) * 1e6,
+                scores=scores, ids=ids, status=status, queue_us=0.0,
             )
 
         raise DegradationExhaustedError(

@@ -4,7 +4,7 @@ Every serving layer — the bare ``RetrievalEngine``, the hardened
 ``GuardedEngine``, and the microbatching ``MicrobatchServer`` — answers a
 dense request with the same typed object:
 
-    RetrievalResponse(scores, ids, status, queue_us, compute_us)
+    RetrievalResponse(scores, ids, status, queue_us)
 
 replacing the old bare-``(scores, ids)`` vs ``(scores, ids, status)``
 mismatch between the engine and the guard.  ``ServingStatus`` lives here
@@ -15,7 +15,7 @@ it unchanged.
 ``RetrievalResponse`` is a NamedTuple with ``scores`` and ``ids`` first,
 so positional access from the tuple era keeps meaning the same thing:
 ``resp[0]``/``resp[1]`` are the scores/ids panels and ``resp[:2]`` is the
-old pair.  Full-tuple unpacking now yields five fields — legacy
+old pair.  Full-tuple unpacking now yields four fields — legacy
 two/three-target unpacks migrate to ``scores, ids, *_ = resp`` or
 attribute access.
 """
@@ -66,17 +66,16 @@ class RetrievalResponse(NamedTuple):
         step 0); the guard and the batcher stamp what actually happened.
     queue_us: host wall-clock the request spent queued before dispatch —
         0.0 for direct (unbatched) calls; the microbatcher fills it in.
-    compute_us: host wall-clock of the serve itself.  Direct engine calls
-        record dispatch time (device completion is the caller's
-        ``block_until_ready``, as before); the batcher records the
-        blocked panel round-trip.
+
+    Where the time of the serve itself goes is recorded by each layer's
+    ``serve.*`` profiler spans (listed in ``repro.serving``), not on the
+    response.
     """
 
     scores: jax.Array
     ids: jax.Array
     status: ServingStatus
     queue_us: float = 0.0
-    compute_us: float = 0.0
 
     @property
     def pair(self) -> tuple[jax.Array, jax.Array]:

@@ -79,7 +79,7 @@ def test_burst_coalesces_into_one_full_panel_bit_identical(setup):
                                       np.asarray(want_i))
         np.testing.assert_array_equal(np.asarray(resp.scores),
                                       np.asarray(want_s))
-        assert resp.queue_us >= 0.0 and resp.compute_us > 0.0
+        assert resp.queue_us >= 0.0
     s = server.stats()
     assert s["panels"] == 1 and s["panels_by_bucket"][2 * B] == 1
     assert s["padded_rows"] == 0 and s["occupancy_mean"] == 1.0

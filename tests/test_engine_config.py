@@ -203,7 +203,8 @@ def test_response_surface_is_unified(setup):
     assert isinstance(resp, RetrievalResponse)
     assert isinstance(resp.status, ServingStatus)
     assert resp.status.path and not resp.status.degraded
-    assert resp.queue_us == 0.0 and resp.compute_us > 0.0
+    assert resp.queue_us == 0.0
+    assert resp._fields == ("scores", "ids", "status", "queue_us")
     # the tuple-era contract survives: positional access + .pair
     scores, ids, *_ = resp
     assert scores is resp.scores and ids is resp.ids
