@@ -189,6 +189,37 @@ def stack_rows(responses):
     return v, i
 
 
+def report_merge_stats(name, index, params, queries, k: int, n: int) -> None:
+    """The scan kernel's merge counters on the served path's panels: the
+    share of (panel, tile) steps that ran the top-n merge and the
+    insertion rounds per merged tile, for full 8-row panels and for one
+    request padded to a panel with zero rows as the batcher pads it."""
+    import jax.numpy as jnp
+
+    from repro.core.quantized_codes import QuantizedCodes
+    from repro.kernels.fused_encode import fused_encode
+    from repro.kernels.sparse_dot.kernel import BLOCK_N, BLOCK_Q
+    from repro.serving.engine import mode_inv_norms, select_retrieve_fn
+
+    c = index.codes
+    quantized = isinstance(c, QuantizedCodes)
+    fn = select_retrieve_fn(sparse_query=True, quantized=quantized,
+                            int8_scoring=False, use_fused=True)
+    cand = ((c.q_values, c.indices, c.scales) if quantized
+            else (c.values, c.indices))
+    lone = jnp.zeros((BLOCK_Q, queries.shape[1]), queries.dtype)
+    tiles = -(-c.n // BLOCK_N)
+    for label, x in (("full panels", queries),
+                     ("lone", lone.at[0].set(queries[0]))):
+        q = fused_encode(x, params["w_enc"], params["b_enc"], k)
+        *_, stats = fn(*cand, mode_inv_norms(index, "sparse"), q.values,
+                       q.indices, c.dim, n=n, with_stats=True)
+        merged, rounds = np.asarray(stats).sum(axis=0)
+        log(f"[merge] {name} {label}: {merged} of {len(stats) * tiles} "
+            f"panel tiles merged ({merged / (len(stats) * tiles):.4f}), "
+            f"{rounds / max(merged, 1):.2f} rounds per merged tile")
+
+
 def one_chip() -> None:
     import jax
 
@@ -236,6 +267,7 @@ def one_chip() -> None:
                               config=EngineConfig(use_kernel=False))
         ref_v, ref_i, *_ = ref.retrieve_dense(queries, TOP_N)
         assert_matches_ref(name, got_v, got_i, ref_v, ref_i)
+        report_merge_stats(name, index, params, queries, cfg.k, TOP_N)
         log(f"[quality] {name}: recall@{TOP_N} vs dense truth "
             f"{recall_at_n(got_i, truth):.4f}")
         del engine, ref, index, responses

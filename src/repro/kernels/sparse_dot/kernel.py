@@ -19,20 +19,30 @@ Generation 2 — ``fused_retrieve_pallas`` (score + select, streaming top-n):
     The per-query-panel running best-(score, id) buffers — shape
     (BLOCK_Q, n) — live in the revisited output block (VMEM-resident across
     the whole candidate axis, index map ignores the candidate grid index)
-    and are merged with each tile's (BLOCK_Q, BLOCK_N) scores by an n-step
-    select-max-and-mask sweep over the n + BLOCK_N concatenated candidates.
+    and each tile's (BLOCK_Q, BLOCK_N) scores are inserted into them.
     Only (Q, n) values + ids are ever written back.
   * Per-candidate reciprocal norms stream alongside the values
     ((BLOCK_N, 1) tiles) and fold the cosine denominator into the epilogue;
     the per-query 1/‖q‖ factor is applied outside (it cannot reorder a
     query row's top-n).
   * A whole-tile skip: if no score in the tile beats any query's current
-    n-th best, the merge sweep is predicated off (`pl.when`) — the common
-    case once the buffers warm up on impact-ordered or clustered data.
+    n-th best, the merge is predicated off (`pl.when`) — the common case
+    once the buffers warm up on impact-ordered or clustered data.
+  * Threshold-gated insertion (``_merge_top_n``): a merging tile runs as
+    many rounds as its most-improved row has scores above that row's n-th
+    best (at most n), not n.  Each round moves, for every row at once,
+    the row's best remaining tile score into its score-descending buffer
+    at p = #(buffer >= score) and shifts the tail one lane right; a row
+    whose best remaining score cannot enter (p = n) is left as it is.  A
+    warm buffer takes one or two rounds; an adversarial order (ascending
+    scores) takes n, each round cheaper than one of the n-round
+    select-max sweep it replaces.
   * Tie semantics match `jax.lax.top_k` (lowest candidate id wins): tiles
-    arrive in ascending-id order, the running buffer precedes the tile in
-    the concatenated sweep, and the sweep selects the *first* position
-    attaining the max.
+    arrive in ascending-id order, so a buffered score equal to a tile's
+    is ahead of it (the insert lands after every equal entry), and within
+    a tile each round takes the *first* position attaining the max.
+    Slots left at -inf (fewer than n finite candidates so far) carry the
+    id that led the buffer before the merge, as the sweep did.
   * Padded candidate rows (N % BLOCK_N) are masked to -inf inside the
     kernel via the static true row count, so they can never surface even
     when all real scores are negative.
@@ -103,7 +113,8 @@ VMEM budget per grid step (f32):
   + 8·BLOCK_N·k            candidate tile     (256 × 32  →  64 KiB)
   + 4·BLOCK_N              reciprocal norms   (           →   1 KiB)
   + 8·BLOCK_Q·n            output best-(v,id) (8 × 64    →   4 KiB)
-  + 8·BLOCK_Q·(n+BLOCK_N)  merge sweep temp   (8 × 320   →  20 KiB)
+  + 8·BLOCK_Q·128⌈n/128⌉   merge buffer temp  (8 × 128   →   8 KiB)
+  + 4·BLOCK_Q·BLOCK_N      merge tile temp    (8 × 256   →   8 KiB)
   ≈ 0.25 MiB at defaults — far under the ~16 MiB/core VMEM ceiling; h up
   to ~128k or BLOCK_Q up to ~256 stay in budget.  Generation 3 swaps the
   query-panel *input* block for a same-size (BLOCK_Q, h) scratch plus two
@@ -122,7 +133,7 @@ Generation 6 — ``fused_retrieve_gathered_*_pallas`` (gather-aware re-rank):
     ``_score_tile`` on 3-D bricks): round j's index slab (BLOCK_Q,
     BLOCK_N) addresses each query row's own panel lanes — same gather
     and FMA count.  The epilogue folds a per-(query, candidate) norm tile
-    instead of a broadcast norm row; merge sweep, padding masks,
+    instead of a broadcast norm row; the merge, padding masks,
     whole-tile skip and tie semantics are generation 2's unchanged.
   * Each query row's arithmetic is op-for-op the per-query generation on
     its gathered sub-arrays, so batched stage 2 is bit-identical — scores,
@@ -142,9 +153,14 @@ interpret mode accepts all of the refused forms):
     data only: ``_lane_gather`` visits the (BLOCK_Q, h) panel one 128-lane
     chunk at a time and keeps the lanes whose index falls in the chunk —
     k·(h/128)·(BLOCK_N/128) gathers per tile; int8 panels are held int32.
-  * No unaligned lane concatenation: the merge keeps [best | tile] as two
-    arrays.  The select-max-and-mask sweep uses only max / min / where /
-    broadcasted_iota — no in-kernel sort or top_k primitive needed.
+  * The merge keeps the buffer and the tile as two arrays.  The buffer is
+    padded in-kernel to whole 128-lane vregs so the insertion's one-lane
+    shift is a lane rotation (``pltpu.roll``); the rest is max / min /
+    sum / where / broadcasted_iota — no in-kernel sort or top_k.  A round
+    runs three cross-lane reductions (tile max, its first position, the
+    insert position), two of them side by side.
+  * The round count is a traced bound: a count of scores above each row's
+    n-th best, reduced to one scalar the way ``pl.when`` reduces its test.
 """
 from __future__ import annotations
 
@@ -257,48 +273,57 @@ def sparse_dot_pallas(
     )(values, indices, q.astype(jnp.float32))
 
 
-def _merge_top_n(best_v, best_i, tile_v, tile_i, n):
-    """n select-max-and-mask rounds over the candidates [best | tile].
+def _merge_top_n(best_v, best_i, tile_v, base, rounds):
+    """Threshold-gated insertion of a tile into the running top-n.
 
-    Returns the refreshed, score-descending (ties: id-ascending) top-n —
-    lax.top_k over the n + BLOCK_N candidates.  The two halves stay
-    separate arrays (an unaligned lane concat does not lower) and round j
-    lands in output lane j by a masked select, not a dynamic store.
+    best_v/best_i: the (BLOCK_Q, n) score-descending (ties: id-ascending)
+    buffers; tile_v: the (BLOCK_Q, BLOCK_N) tile scores, whose ids are
+    ``base`` + lane.  Each of ``rounds`` rounds takes every row's best
+    remaining tile score (first lane on ties) and inserts it at
+    p = #(buffer >= score), shifting the tail one lane right; p = n means
+    it cannot enter, and that row is left as it is.  Scores come out in
+    descending order and the threshold only rises, so once a row's best
+    remaining score fails, every later one does: ``rounds`` = the largest
+    per-row count of tile scores above the row's n-th best (capped at n)
+    is enough.  Returns lax.top_k over the n + BLOCK_N candidates, with
+    slots left at -inf carrying the buffer's first id from before the
+    merge.  The buffer is padded to whole vregs for the lane rotation.
     """
-    bq, bn = tile_v.shape
-    width = n + bn
-    pos_b = jax.lax.broadcasted_iota(jnp.int32, (bq, n), 1)
-    pos_t = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1) + n
+    bq, n = best_v.shape
+    bn = tile_v.shape[1]
+    pad = ((0, 0), (0, -n % LANES))
+    bv = jnp.pad(best_v, pad, constant_values=_NEG_INF)
+    bi = jnp.pad(best_i, pad)
+    pos_b = jax.lax.broadcasted_iota(jnp.int32, bv.shape, 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
+    in_buf = pos_b < n
 
-    def first(v, pos, m):
-        return jnp.min(jnp.where(v == m, pos, width), axis=1, keepdims=True)
+    def step(_, carry):
+        bv, bi, tv = carry
+        m = jnp.max(tv, axis=1, keepdims=True)                    # (BQ, 1)
+        at = jnp.min(jnp.where(tv == m, pos_t, bn), axis=1, keepdims=True)
+        p = jnp.sum(jnp.where(in_buf & (bv >= m), 1, 0), axis=1,
+                    keepdims=True)                                 # insert at
+        bv = jnp.where(pos_b < p, bv,
+                       jnp.where(pos_b == p, m, pltpu.roll(bv, 1, 1)))
+        bi = jnp.where(pos_b < p, bi,
+                       jnp.where(pos_b == p, base + at, pltpu.roll(bi, 1, 1)))
+        return bv, bi, jnp.where(pos_t == at, _NEG_INF, tv)
 
-    def take(pos, at, ids):
-        return jnp.sum(jnp.where(pos == at, ids, 0), axis=1, keepdims=True)
-
-    def step(j, carry):
-        bv, tv, out_v, out_i = carry
-        m = jnp.maximum(jnp.max(bv, axis=1, keepdims=True),
-                        jnp.max(tv, axis=1, keepdims=True))        # (BQ, 1)
-        at = jnp.minimum(first(bv, pos_b, m), first(tv, pos_t, m))  # argmax
-        sel = take(pos_b, at, best_i) + take(pos_t, at, tile_i)
-        out_v = jnp.where(pos_b == j, m, out_v)
-        out_i = jnp.where(pos_b == j, sel, out_i)
-        return (jnp.where(pos_b == at, _NEG_INF, bv),
-                jnp.where(pos_t == at, _NEG_INF, tv), out_v, out_i)
-
-    _, _, out_v, out_i = jax.lax.fori_loop(
-        0, n, step, (best_v, tile_v, best_v, best_i))
-    return out_v, out_i
+    bv, bi, _ = jax.lax.fori_loop(0, rounds, step, (bv, bi, tile_v))
+    out_v = bv[:, :n]
+    return out_v, jnp.where(out_v == _NEG_INF, best_i[:, :1], bi[:, :n])
 
 
-def _init_best(out_v_ref, out_i_ref):
+def _init_best(out_v_ref, out_i_ref, stats_ref=None):
     out_v_ref[...] = jnp.full(out_v_ref.shape, _NEG_INF, jnp.float32)
     out_i_ref[...] = jnp.zeros(out_i_ref.shape, jnp.int32)
+    if stats_ref is not None:
+        stats_ref[...] = jnp.zeros(stats_ref.shape, jnp.int32)
 
 
 def _mask_fold_merge(scores, inv, nb, out_v_ref, out_i_ref, *,
-                     n, n_valid, block_n, alive=None):
+                     n, n_valid, block_n, alive=None, stats_ref=None):
     """Shared streaming-top-n tile epilogue: fold the reciprocal candidate
     norms, mask padded rows by id, and merge the tile into the
     VMEM-resident running best buffers (whole-tile skip when nothing beats
@@ -309,21 +334,49 @@ def _mask_fold_merge(scores, inv, nb, out_v_ref, out_i_ref, *,
     deletion mask — rides the padding mask: deleted rows score -inf
     exactly like padding, and a fully-deleted tile takes the same
     whole-tile skip (every score is -inf, so nothing can beat the current
-    n-th best)."""
+    n-th best).  ``stats_ref`` — a (BLOCK_Q, LANES) int32 block — counts
+    the panel's merged tiles in lane 0 and insertion rounds in lane 1."""
     scores = scores * inv                                          # fold 1/‖c‖
     bq, bn = scores.shape
-    ids = nb * block_n + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
+    base = nb * block_n
+    ids = base + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
     keep = ids < n_valid                                           # mask padding
     if alive is not None:
         keep = keep & (alive > 0.0)                                # mask deletions
     scores = jnp.where(keep, scores, _NEG_INF)
 
-    cur_min = out_v_ref[:, n - 1:n]                                # n-th best
+    beats = scores > out_v_ref[:, n - 1:n]                         # n-th best
 
-    @pl.when(jnp.any(scores > cur_min))
+    @pl.when(jnp.any(beats))
     def _merge():
+        per_row = jnp.sum(beats.astype(jnp.int32), axis=1)
+        rounds = jnp.minimum(jnp.max(per_row), n)
         out_v_ref[...], out_i_ref[...] = _merge_top_n(
-            out_v_ref[...], out_i_ref[...], scores, ids, n)
+            out_v_ref[...], out_i_ref[...], scores, base, rounds)
+        if stats_ref is not None:
+            lane = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape, 1)
+            stats_ref[...] += jnp.where(lane == 0, 1,
+                                        jnp.where(lane == 1, rounds, 0))
+
+
+def _best_outputs(nq, n, block_q, with_stats=False):
+    """``pallas_call`` output specs of a fused retrieve: the (Q, n) running
+    best scores and ids, each panel's block revisited along the candidate
+    axis, and with ``with_stats`` a (Q, LANES) int32 merge-counter block
+    (``_mask_fold_merge``; read back by ``_panel_stats``)."""
+    specs = [pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0))] * 2
+    shapes = [jax.ShapeDtypeStruct((nq, n), jnp.float32),
+              jax.ShapeDtypeStruct((nq, n), jnp.int32)]
+    if with_stats:
+        specs.append(pl.BlockSpec((block_q, LANES), lambda qi, i: (qi, 0)))
+        shapes.append(jax.ShapeDtypeStruct((nq, LANES), jnp.int32))
+    return {"out_specs": specs, "out_shape": shapes}
+
+
+def _panel_stats(stats, block_q):
+    """(Q, LANES) counter block -> (Q / block_q, 2) int32: per query panel,
+    the tiles that ran the merge and the insertion rounds they ran."""
+    return stats[::block_q, :2]
 
 
 def _make_retrieve_kernel(n: int, n_valid: int, block_n: int):
@@ -375,14 +428,7 @@ def fused_retrieve_pallas(
             pl.BlockSpec((block_n, 1), lambda qi, i: (i, 0)),
             pl.BlockSpec((block_q, h), lambda qi, i: (qi, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         interpret=interpret,
     )(values, indices, inv_norms, q.astype(jnp.float32))
     return out_v, out_i
@@ -408,32 +454,42 @@ def _densify_panel(q_vals, q_idx, h: int):
                              jnp.zeros((bq, h), jnp.float32))
 
 
+def _sparse_q_refs(rest, with_alive, with_stats):
+    """Split a sparse-query kernel's trailing refs: [alive], query values,
+    query ids, best scores, best ids, [stats], then the scratch refs."""
+    alive_ref = rest[0] if with_alive else None
+    qv_ref, qi_ref, out_v_ref, out_i_ref, *rest = rest[with_alive:]
+    stats_ref = rest[0] if with_stats else None
+    return (alive_ref, qv_ref, qi_ref, out_v_ref, out_i_ref, stats_ref,
+            rest[with_stats:])
+
+
 def _make_retrieve_sparse_q_kernel(n: int, n_valid: int, block_n: int, h: int,
-                                   with_alive: bool = False):
+                                   with_alive: bool = False,
+                                   with_stats: bool = False):
     def kernel(vals_ref, idx_ref, inv_ref, *rest):
-        if with_alive:
-            alive_ref, qv_ref, qi_ref, out_v_ref, out_i_ref, panel_ref = rest
-        else:
-            qv_ref, qi_ref, out_v_ref, out_i_ref, panel_ref = rest
-            alive_ref = None
+        (alive_ref, qv_ref, qi_ref, out_v_ref, out_i_ref, stats_ref,
+         (panel_ref,)) = _sparse_q_refs(rest, with_alive, with_stats)
         nb = pl.program_id(1)
 
         @pl.when(nb == 0)
         def _init():
-            _init_best(out_v_ref, out_i_ref)
+            _init_best(out_v_ref, out_i_ref, stats_ref)
             panel_ref[...] = _densify_panel(qv_ref[...], qi_ref[...], h)
 
         scores = _score_tile(vals_ref[...], idx_ref[...], panel_ref[...])
         _mask_fold_merge(scores, inv_ref[...].T, nb, out_v_ref, out_i_ref,
                          n=n, n_valid=n_valid, block_n=block_n,
-                         alive=None if alive_ref is None else alive_ref[...].T)
+                         alive=None if alive_ref is None else alive_ref[...].T,
+                         stats_ref=stats_ref)
 
     return kernel
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("h", "n", "n_valid", "interpret", "block_n", "block_q"),
+    static_argnames=("h", "n", "n_valid", "interpret", "block_n", "block_q",
+                     "with_stats"),
 )
 def fused_retrieve_sparse_q_pallas(
     values: jax.Array,
@@ -449,7 +505,8 @@ def fused_retrieve_sparse_q_pallas(
     block_n: int = BLOCK_N,
     block_q: int = BLOCK_Q,
     alive: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
+    with_stats: bool = False,
+) -> tuple[jax.Array, ...]:
     """Sparse-query fused score+select: (Q, n) best (scores, candidate ids).
 
     values (N, k) f32, indices (N, k) i32, inv_norms (N, 1) f32, q_values
@@ -459,7 +516,9 @@ def fused_retrieve_sparse_q_pallas(
     query HBM traffic is the (Q, kq) codes — never (Q, h).  ``alive``,
     when given, is an (N, 1) f32 1.0/0.0 deletion mask: dead rows are
     masked to -inf alongside padding, and fully-dead tiles take the
-    whole-tile skip.
+    whole-tile skip.  ``with_stats`` appends a (Q / block_q, 2) int32
+    array: per query panel, the tiles that ran the merge and the
+    insertion rounds they ran.
     """
     N, k = values.shape
     nq = q_values.shape[0]
@@ -477,23 +536,19 @@ def fused_retrieve_sparse_q_pallas(
     if alive is not None:
         in_specs.insert(3, pl.BlockSpec((block_n, 1), lambda qi, i: (i, 0)))
         operands.insert(3, alive)
-    out_v, out_i = pl.pallas_call(
+    out = pl.pallas_call(
         _make_retrieve_sparse_q_kernel(n, n_valid, block_n, h,
-                                       with_alive=alive is not None),
+                                       with_alive=alive is not None,
+                                       with_stats=with_stats),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q, with_stats),
         scratch_shapes=[pltpu.VMEM((block_q, h), jnp.float32)],
         interpret=interpret,
     )(*operands)
-    return out_v, out_i
+    if with_stats:
+        return out[0], out[1], _panel_stats(out[2], block_q)
+    return tuple(out)
 
 
 def _dequant_tile(q_vals, idx, scales):
@@ -564,47 +619,40 @@ def fused_retrieve_quantized_pallas(
             pl.BlockSpec((block_n, 1), lambda qi, i: (i, 0)),
             pl.BlockSpec((block_q, h), lambda qi, i: (qi, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         interpret=interpret,
     )(q_values, indices, scales, inv_norms, q.astype(jnp.float32))
     return out_v, out_i
 
 
 def _make_retrieve_quantized_sparse_q_kernel(
-    n: int, n_valid: int, block_n: int, h: int, with_alive: bool = False
+    n: int, n_valid: int, block_n: int, h: int, with_alive: bool = False,
+    with_stats: bool = False,
 ):
     def kernel(qvals_ref, idx_ref, scale_ref, inv_ref, *rest):
-        if with_alive:
-            alive_ref, qv_ref, qi_ref, out_v_ref, out_i_ref, panel_ref = rest
-        else:
-            qv_ref, qi_ref, out_v_ref, out_i_ref, panel_ref = rest
-            alive_ref = None
+        (alive_ref, qv_ref, qi_ref, out_v_ref, out_i_ref, stats_ref,
+         (panel_ref,)) = _sparse_q_refs(rest, with_alive, with_stats)
         nb = pl.program_id(1)
 
         @pl.when(nb == 0)
         def _init():
-            _init_best(out_v_ref, out_i_ref)
+            _init_best(out_v_ref, out_i_ref, stats_ref)
             panel_ref[...] = _densify_panel(qv_ref[...], qi_ref[...], h)
 
         vals, idx = _dequant_tile(qvals_ref[...], idx_ref[...], scale_ref[...])
         scores = _score_tile(vals, idx, panel_ref[...])
         _mask_fold_merge(scores, inv_ref[...].T, nb, out_v_ref, out_i_ref,
                          n=n, n_valid=n_valid, block_n=block_n,
-                         alive=None if alive_ref is None else alive_ref[...].T)
+                         alive=None if alive_ref is None else alive_ref[...].T,
+                         stats_ref=stats_ref)
 
     return kernel
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("h", "n", "n_valid", "interpret", "block_n", "block_q"),
+    static_argnames=("h", "n", "n_valid", "interpret", "block_n", "block_q",
+                     "with_stats"),
 )
 def fused_retrieve_quantized_sparse_q_pallas(
     q_values: jax.Array,
@@ -621,15 +669,16 @@ def fused_retrieve_quantized_sparse_q_pallas(
     block_n: int = BLOCK_N,
     block_q: int = BLOCK_Q,
     alive: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
+    with_stats: bool = False,
+) -> tuple[jax.Array, ...]:
     """Quantized candidates × sparse query codes: the full-compression
     serving kernel.  Candidate tiles stream int8/int16 and dequantize in
     VMEM; the (Q, kq) query codes densify into the (block_q, h) VMEM
     scratch panel (generation 3).  Neither an fp32 index nor a dense query
     panel ever exists in HBM.  Bit-identical to
     ``fused_retrieve_sparse_q_pallas`` over the dequantized arrays.
-    ``alive``: optional (N, 1) f32 1.0/0.0 deletion mask (see the fp32
-    sparse-q wrapper).
+    ``alive``: optional (N, 1) f32 1.0/0.0 deletion mask, ``with_stats``
+    the merge counters (see the fp32 sparse-q wrapper for both).
     """
     N, k = q_values.shape
     nq = query_values.shape[0]
@@ -648,23 +697,19 @@ def fused_retrieve_quantized_sparse_q_pallas(
     if alive is not None:
         in_specs.insert(4, pl.BlockSpec((block_n, 1), lambda qi, i: (i, 0)))
         operands.insert(4, alive)
-    out_v, out_i = pl.pallas_call(
+    out = pl.pallas_call(
         _make_retrieve_quantized_sparse_q_kernel(n, n_valid, block_n, h,
-                                                 with_alive=alive is not None),
+                                                 with_alive=alive is not None,
+                                                 with_stats=with_stats),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q, with_stats),
         scratch_shapes=[pltpu.VMEM((block_q, h), jnp.float32)],
         interpret=interpret,
     )(*operands)
-    return out_v, out_i
+    if with_stats:
+        return out[0], out[1], _panel_stats(out[2], block_q)
+    return tuple(out)
 
 
 def _store_quantized_panel(panel, qp_ref, qs_ref):
@@ -739,14 +784,7 @@ def fused_retrieve_quantized_mxu_pallas(
             pl.BlockSpec((block_n, 1), lambda qi, i: (i, 0)),
             pl.BlockSpec((block_q, h), lambda qi, i: (qi, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         scratch_shapes=[
             pltpu.VMEM((block_q, h), jnp.int32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -760,12 +798,8 @@ def _make_retrieve_quantized_mxu_sparse_q_kernel(
     n: int, n_valid: int, block_n: int, h: int, with_alive: bool = False
 ):
     def kernel(qvals_ref, idx_ref, scale_ref, inv_ref, *rest):
-        if with_alive:
-            (alive_ref, qv_ref, qi_ref,
-             out_v_ref, out_i_ref, qp_ref, qs_ref) = rest
-        else:
-            qv_ref, qi_ref, out_v_ref, out_i_ref, qp_ref, qs_ref = rest
-            alive_ref = None
+        (alive_ref, qv_ref, qi_ref, out_v_ref, out_i_ref, _,
+         (qp_ref, qs_ref)) = _sparse_q_refs(rest, with_alive, False)
         nb = pl.program_id(1)
 
         @pl.when(nb == 0)
@@ -839,14 +873,7 @@ def fused_retrieve_quantized_mxu_sparse_q_pallas(
         ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         scratch_shapes=[
             pltpu.VMEM((block_q, h), jnp.int32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -927,14 +954,7 @@ def fused_retrieve_gathered_sparse_q_pallas(
             pl.BlockSpec((block_q, kq), lambda qi, i: (qi, 0)),
             pl.BlockSpec((block_q, kq), lambda qi, i: (qi, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         scratch_shapes=[pltpu.VMEM((block_q, h), jnp.float32)],
         interpret=interpret,
     )(values, indices, inv_norms,
@@ -1017,14 +1037,7 @@ def fused_retrieve_gathered_quantized_sparse_q_pallas(
             pl.BlockSpec((block_q, kq), lambda qi, i: (qi, 0)),
             pl.BlockSpec((block_q, kq), lambda qi, i: (qi, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         scratch_shapes=[pltpu.VMEM((block_q, h), jnp.float32)],
         interpret=interpret,
     )(q_values, indices, scales, inv_norms,
@@ -1099,14 +1112,7 @@ def fused_retrieve_gathered_quantized_mxu_sparse_q_pallas(
             pl.BlockSpec((block_q, kq), lambda qi, i: (qi, 0)),
             pl.BlockSpec((block_q, kq), lambda qi, i: (qi, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-            pl.BlockSpec((block_q, n), lambda qi, i: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, n), jnp.float32),
-            jax.ShapeDtypeStruct((nq, n), jnp.int32),
-        ],
+        **_best_outputs(nq, n, block_q),
         scratch_shapes=[
             pltpu.VMEM((block_q, h), jnp.int32),
             pltpu.VMEM((block_q, 1), jnp.float32),

@@ -133,7 +133,9 @@ def fused_retrieve(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("h", "n", "block_n", "block_q", "interpret")
+    jax.jit,
+    static_argnames=("h", "n", "block_n", "block_q", "interpret",
+                     "with_stats"),
 )
 def fused_retrieve_sparse_q(
     values: jax.Array,
@@ -148,7 +150,8 @@ def fused_retrieve_sparse_q(
     block_q: int = BLOCK_Q,
     interpret: bool | None = None,
     alive: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
+    with_stats: bool = False,
+) -> tuple[jax.Array, ...]:
     """Sparse-query fused score+select -> ((Q, n) scores, (Q, n) ids).
 
     values (N, k) f32, indices (N, k) i32, inv_norms (N,) f32, q_values
@@ -159,6 +162,9 @@ def fused_retrieve_sparse_q(
     optional (N,) 1.0/0.0 row-liveness mask (segmented-index deletions) —
     dead rows are masked to -inf exactly like padding, so they can never
     appear among the top-n while live rows' scores/ids are untouched.
+    ``with_stats=True`` appends the kernel's merge counters, a
+    (ceil(Q / block_q), 2) int32 array: per query panel, the tiles that
+    ran the top-n merge and the insertion rounds they ran.
     """
     squeeze = q_values.ndim == 1
     if squeeze:
@@ -173,7 +179,7 @@ def fused_retrieve_sparse_q(
     if qpad:
         q_values = jnp.pad(q_values, ((0, qpad), (0, 0)))
         q_indices = jnp.pad(q_indices, ((0, qpad), (0, 0)))
-    out_v, out_i = fused_retrieve_sparse_q_pallas(
+    out_v, out_i, *stats = fused_retrieve_sparse_q_pallas(
         values,
         indices,
         inv_norms.astype(jnp.float32).reshape(-1, 1),
@@ -187,9 +193,11 @@ def fused_retrieve_sparse_q(
         block_q=block_q,
         alive=(None if alive is None
                else alive.astype(jnp.float32).reshape(-1, 1)),
+        with_stats=with_stats,
     )
     out_v, out_i = out_v[:nq], out_i[:nq]
-    return (out_v[0], out_i[0]) if squeeze else (out_v, out_i)
+    out = (out_v[0], out_i[0]) if squeeze else (out_v, out_i)
+    return out + tuple(stats)
 
 
 @functools.partial(
@@ -247,7 +255,9 @@ def fused_retrieve_quantized(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("h", "n", "block_n", "block_q", "interpret")
+    jax.jit,
+    static_argnames=("h", "n", "block_n", "block_q", "interpret",
+                     "with_stats"),
 )
 def fused_retrieve_quantized_sparse_q(
     q_values: jax.Array,
@@ -263,7 +273,8 @@ def fused_retrieve_quantized_sparse_q(
     block_q: int = BLOCK_Q,
     interpret: bool | None = None,
     alive: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
+    with_stats: bool = False,
+) -> tuple[jax.Array, ...]:
     """Quantized candidates × sparse query codes -> ((Q, n) scores, ids).
 
     The full-compression serving kernel: candidate tiles stream int8/int16
@@ -271,8 +282,8 @@ def fused_retrieve_quantized_sparse_q(
     the (Q, kq) codes and (Q, n) results touch HBM on the query side, and
     the index never exists in fp32.  Bit-identical to
     ``fused_retrieve_sparse_q`` over the dequantized arrays.  ``alive``:
-    optional (N,) 1.0/0.0 row-liveness mask (see
-    ``fused_retrieve_sparse_q``).
+    optional (N,) 1.0/0.0 row-liveness mask, ``with_stats``: the merge
+    counters (see ``fused_retrieve_sparse_q`` for both).
     """
     squeeze = query_values.ndim == 1
     if squeeze:
@@ -289,7 +300,7 @@ def fused_retrieve_quantized_sparse_q(
     if qpad:
         query_values = jnp.pad(query_values, ((0, qpad), (0, 0)))
         query_indices = jnp.pad(query_indices, ((0, qpad), (0, 0)))
-    out_v, out_i = fused_retrieve_quantized_sparse_q_pallas(
+    out_v, out_i, *stats = fused_retrieve_quantized_sparse_q_pallas(
         q_values,
         indices,
         scales.astype(jnp.float32).reshape(-1, 1),
@@ -304,9 +315,11 @@ def fused_retrieve_quantized_sparse_q(
         block_q=block_q,
         alive=(None if alive is None
                else alive.astype(jnp.float32).reshape(-1, 1)),
+        with_stats=with_stats,
     )
     out_v, out_i = out_v[:nq], out_i[:nq]
-    return (out_v[0], out_i[0]) if squeeze else (out_v, out_i)
+    out = (out_v[0], out_i[0]) if squeeze else (out_v, out_i)
+    return out + tuple(stats)
 
 
 @functools.partial(

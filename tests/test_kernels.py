@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core.quantized_codes import dequantize_codes, quantize_codes
 from repro.core.sae import normalize_input
@@ -15,8 +16,10 @@ from repro.kernels.embedding_bag.ops import embedding_bag
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 from repro.kernels.fused_encode.ops import fused_encode
 from repro.kernels.fused_encode.ref import fused_encode_ref
+from repro.kernels.sparse_dot import kernel as sparse_dot_kernel
 from repro.kernels.sparse_dot.ops import (
     fused_retrieve,
+    fused_retrieve_gathered_sparse_q,
     fused_retrieve_quantized,
     fused_retrieve_quantized_mxu,
     fused_retrieve_quantized_mxu_sparse_q,
@@ -529,6 +532,227 @@ def test_quantized_mxu_single_query_and_validation():
         fused_retrieve_quantized_mxu_sparse_q(
             qc.q_values, qc.indices, qc.scales, inv, qv, qi, 128, n=97
         )
+
+
+# ------------------------------------------- streaming top-n merge identity
+# The tile epilogue the kernels ran before the threshold-gated insertion:
+# n select-max-and-mask rounds over [best | tile] for every merging tile.
+# Kept as the oracle of bit-identity (values, ids, -inf slots included).
+def _sweep_merge_top_n(best_v, best_i, tile_v, tile_i, n):
+    bq, bn = tile_v.shape
+    width = n + bn
+    pos_b = jax.lax.broadcasted_iota(jnp.int32, (bq, n), 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1) + n
+
+    def first(v, pos, m):
+        return jnp.min(jnp.where(v == m, pos, width), axis=1, keepdims=True)
+
+    def take(pos, at, ids):
+        return jnp.sum(jnp.where(pos == at, ids, 0), axis=1, keepdims=True)
+
+    def step(j, carry):
+        bv, tv, out_v, out_i = carry
+        m = jnp.maximum(jnp.max(bv, axis=1, keepdims=True),
+                        jnp.max(tv, axis=1, keepdims=True))
+        at = jnp.minimum(first(bv, pos_b, m), first(tv, pos_t, m))
+        sel = take(pos_b, at, best_i) + take(pos_t, at, tile_i)
+        out_v = jnp.where(pos_b == j, m, out_v)
+        out_i = jnp.where(pos_b == j, sel, out_i)
+        return (jnp.where(pos_b == at, -jnp.inf, bv),
+                jnp.where(pos_t == at, -jnp.inf, tv), out_v, out_i)
+
+    _, _, out_v, out_i = jax.lax.fori_loop(
+        0, n, step, (best_v, tile_v, best_v, best_i))
+    return out_v, out_i
+
+
+def _sweep_mask_fold_merge(scores, inv, nb, out_v_ref, out_i_ref, *,
+                           n, n_valid, block_n, alive=None, stats_ref=None):
+    scores = scores * inv
+    bq, bn = scores.shape
+    ids = nb * block_n + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
+    keep = ids < n_valid
+    if alive is not None:
+        keep = keep & (alive > 0.0)
+    scores = jnp.where(keep, scores, -jnp.inf)
+
+    @pl.when(jnp.any(scores > out_v_ref[:, n - 1:n]))
+    def _merge():
+        out_v_ref[...], out_i_ref[...] = _sweep_merge_top_n(
+            out_v_ref[...], out_i_ref[...], scores, ids, n)
+
+
+@pytest.fixture
+def with_sweep_epilogue(monkeypatch):
+    """Run a kernel wrapper with the select-max sweep epilogue swapped in
+    (traces are cleared on both sides, so nothing cached crosses over)."""
+    def run(fn, *args, **kw):
+        jax.clear_caches()
+        with monkeypatch.context() as m:
+            m.setattr(sparse_dot_kernel, "_mask_fold_merge",
+                      _sweep_mask_fold_merge)
+            out = jax.block_until_ready(fn(*args, **kw))
+        jax.clear_caches()
+        return out
+    return run
+
+
+MERGE_H, MERGE_N = 128, 16 * 256 + 37   # >= 16 tiles, ragged last tile
+
+
+def _merge_case(order, q=9, seed=0):
+    """Integer-valued codes over 16 latents with unit norms, so every score
+    is an exact small integer (no rounding: lax.top_k on numpy scores is
+    exact) and ties are common.  Row order: "random", "ascending" /
+    "descending" by query 0's score (every tile inserts n / almost none
+    merges), "tied" (every row the same code), "repeated" (a 40-row block
+    repeated: ties across tile boundaries).  Returns values, ids, query
+    codes and the exact (q, N) scores."""
+    rng = np.random.default_rng(seed)
+    k = 4
+    vals = rng.integers(-8, 9, (MERGE_N, k)).astype(np.float32)
+    idx = rng.integers(0, 16, (MERGE_N, k)).astype(np.int32)
+    if order == "tied":
+        vals[:], idx[:] = vals[0], idx[0]
+    elif order == "repeated":
+        vals, idx = np.resize(vals[:40], vals.shape), np.resize(idx[:40],
+                                                                 idx.shape)
+    qv = rng.integers(-8, 9, (q, k)).astype(np.float32)
+    qi = rng.integers(0, 16, (q, k)).astype(np.int32)
+    dense = np.zeros((q, MERGE_H), np.float32)
+    np.add.at(dense, (np.arange(q)[:, None], qi), qv)
+
+    def exact(v, i):
+        return np.einsum("nk,qnk->qn", v, dense[:, i])
+
+    if order in ("ascending", "descending"):
+        s0 = exact(vals, idx)[0]
+        perm = np.argsort(-s0 if order == "descending" else s0, kind="stable")
+        vals, idx = vals[perm], idx[perm]
+    return vals, idx, qv, qi, exact(vals, idx)
+
+
+def _merge_retrieve(gen, vals, idx, qv, qi, topn, alive=None, **kw):
+    """The sparse-query generation ``gen`` on the integer case (unit norms,
+    unit dequant scales), through its public ops wrapper."""
+    n_rows, inv = vals.shape[0], jnp.ones(vals.shape[0], jnp.float32)
+    if gen == 3:
+        return fused_retrieve_sparse_q(vals, idx, inv, qv, qi, MERGE_H,
+                                       n=topn, alive=alive, **kw)
+    if gen == 6:   # the same catalog gathered for every query
+        v, i, w = (jnp.broadcast_to(a, (qv.shape[0],) + a.shape)
+                   for a in (vals, idx, inv))
+        return fused_retrieve_gathered_sparse_q(v, i, w, qv, qi, MERGE_H,
+                                                n=topn)
+    q8, i16 = vals.astype(np.int8), idx.astype(np.int16)
+    ones = jnp.ones(n_rows, jnp.float32)
+    fn = (fused_retrieve_quantized_sparse_q if gen == 4
+          else fused_retrieve_quantized_mxu_sparse_q)
+    return fn(q8, i16, ones, inv, qv, qi, MERGE_H, n=topn, alive=alive, **kw)
+
+
+def _alive_mask(deleted):
+    """"tiles": tiles 0 and 3 deleted whole, every other row of tile 5;
+    "most": 62 rows alive, one in 67, so most top-n slots stay -inf."""
+    if deleted is None:
+        return None
+    alive = np.ones(MERGE_N, np.float32)
+    if deleted == "tiles":
+        alive[0:256] = alive[768:1024] = 0.0
+        alive[1280:1536:2] = 0.0
+    else:
+        alive[np.arange(MERGE_N) % 67 != 5] = 0.0
+    return alive
+
+
+_MERGE_CASES = [("random", 100, None), ("ascending", 100, None),
+                ("descending", 100, None), ("tied", 100, None),
+                ("repeated", 100, None), ("random", 300, None),
+                ("random", 100, "tiles"), ("descending", 100, "most")]
+
+
+@pytest.mark.parametrize("gen,order,topn,deleted", [
+    (gen, *case) for gen in (3, 4, 5, 6) for case in _MERGE_CASES
+    if not (gen == 6 and case[2])   # gathered panels carry no alive mask
+])
+def test_threshold_merge_identical_to_sweep_and_top_k(
+        with_sweep_epilogue, gen, order, topn, deleted):
+    """The threshold-gated insertion gives exactly what the n-round
+    select-max sweep gave (scores, ids, -inf slots) and lax.top_k's answer
+    (lowest id first on ties), across catalog orders, top-n wider than a
+    tile, a ragged last tile, deletions that empty whole tiles, and fewer
+    live rows than n.  The second query panel is one real row padded with
+    zero rows."""
+    vals, idx, qv, qi, exact = _merge_case(order)
+    alive = _alive_mask(deleted)
+    got_v, got_i = map(np.asarray,
+                       _merge_retrieve(gen, vals, idx, qv, qi, topn, alive))
+    was_v, was_i = map(np.asarray, with_sweep_epilogue(
+        _merge_retrieve, gen, vals, idx, qv, qi, topn, alive))
+    np.testing.assert_array_equal(got_v, was_v)
+    np.testing.assert_array_equal(got_i, was_i)
+
+    if gen == 5:   # int8 scoring: its ref is the bit-identical answer
+        want_v, want_i = map(np.asarray, retrieve_quantized_mxu_sparse_q_ref(
+            vals.astype(np.int8), idx.astype(np.int16),
+            jnp.ones(MERGE_N), jnp.ones(MERGE_N), qv, qi, MERGE_H, n=topn,
+            alive=alive))
+    else:
+        s = exact if alive is None else np.where(alive > 0, exact, -np.inf)
+        want_v, want_i = map(np.asarray, jax.lax.top_k(jnp.asarray(s), topn))
+    live = np.isfinite(want_v)
+    np.testing.assert_array_equal(np.isfinite(got_v), live)
+    np.testing.assert_array_equal(got_v[live], want_v[live])
+    np.testing.assert_array_equal(got_i[live], want_i[live])
+
+
+def _numpy_merge_counts(scores, topn, block_q=8, block_n=256):
+    """Per query panel of the (Q, N) exact scores (rows padded to the
+    panel with zero-query rows, which score 0): tiles in which some row
+    has a score above its running n-th best, and the insertion rounds
+    those tiles need (the largest per-row count above it, at most n)."""
+    q, n_rows = scores.shape
+    rows = -(-q // block_q) * block_q
+    s = np.zeros((rows, -(-n_rows // block_n) * block_n), np.float32)
+    s[:, n_rows:] = -np.inf
+    s[:q, :n_rows] = scores
+    out = []
+    for p in range(0, rows, block_q):
+        panel = s[p:p + block_q]
+        thr = np.full((block_q, 1), -np.inf, np.float32)
+        merged = rounds = 0
+        for t in range(0, s.shape[1], block_n):
+            above = (panel[:, t:t + block_n] > thr).sum(axis=1)
+            if above.any():
+                merged += 1
+                rounds += min(topn, int(above.max()))
+            seen = np.sort(panel[:, :t + block_n], axis=1)[:, ::-1]
+            if seen.shape[1] >= topn:
+                thr = seen[:, topn - 1:topn]
+        out.append((merged, rounds))
+    return np.array(out)
+
+
+@pytest.mark.parametrize("gen", [3, 4])
+@pytest.mark.parametrize("order", ["random", "descending"])
+def test_merge_counters_match_numpy(gen, order):
+    """``with_stats=True`` counts, per query panel, the tiles that ran the
+    merge and the rounds they ran — a numpy count of the same over exact
+    scores.  Over the descending catalog, with one query in every row, a
+    panel merges its first tile only.  The outputs are the same with and
+    without the counters."""
+    vals, idx, qv, qi, exact = _merge_case(order)
+    if order == "descending":
+        qv, qi, exact = (np.repeat(a[:1], 9, axis=0) for a in (qv, qi, exact))
+    v, i, stats = _merge_retrieve(gen, vals, idx, qv, qi, 100,
+                                  with_stats=True)
+    stats = np.asarray(stats)
+    np.testing.assert_array_equal(stats, _numpy_merge_counts(exact, 100))
+    if order == "descending":
+        np.testing.assert_array_equal(stats[:, 0], [1, 1])
+    plain_v, plain_i = _merge_retrieve(gen, vals, idx, qv, qi, 100)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(plain_v))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(plain_i))
 
 
 # ------------------------------------------------------------------ topk_mask

@@ -124,6 +124,17 @@ def test_segmented_retrieve_compiles(on_chip, fn, quantized):
 
 
 @pytest.mark.parametrize("fn,quantized", [
+    (ops.fused_retrieve_sparse_q, False),
+    (ops.fused_retrieve_quantized_sparse_q, True),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_merge_counter_variants_compile(on_chip, fn, quantized):
+    """The served generations with the scan's merge counters output."""
+    S = on_chip
+    _compile(fn, *_candidates(S, quantized), *_query_codes(S), H, n=TOP_N,
+             interpret=False, with_stats=True)
+
+
+@pytest.mark.parametrize("fn,quantized", [
     (ops.fused_retrieve_gathered_sparse_q, False),
     (ops.fused_retrieve_gathered_quantized_sparse_q, True),
     (ops.fused_retrieve_gathered_quantized_mxu_sparse_q, True),
