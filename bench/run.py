@@ -14,7 +14,9 @@ the plain reference in ``bench/yardstick/reference.py``.
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics, read from a profiler trace of the window.  The run
 refuses to start (exit 2, no result line) unless JAX sees as many TPU chips
-as the cell asks for, of a kind in ``bench/peaks.json``.
+as the cell asks for, of a kind in ``bench/peaks.json``, and (exit 2, before
+any device work) where the cell's chips cannot hold its configuration's
+``shards``.
 """
 from __future__ import annotations
 
@@ -72,6 +74,25 @@ class Cell:
 
     def _has(self, metric: dict) -> bool:
         return "workloads" not in metric or self.name in metric["workloads"]
+
+
+def layout_fault(cell: Cell) -> str | None:
+    """Why the cell's chips cannot hold its configuration's shards, or None.
+    A configuration's ``shards`` (1 where it has none) splits the catalog's
+    rows evenly over that many chips, whole ``encode_chunk`` chunks a
+    shard."""
+    cfg = cell.config
+    shards, chips = int(cfg.get("shards", 1)), int(cell.spec["chips"])
+    n, chunk = int(cfg["n_rows"]), int(cfg["encode_chunk"])
+    if shards < 1:
+        return f"shards {shards} is not a positive count"
+    if chips < shards:
+        return (f"the cell asks for {chips} chip(s), its configuration "
+                f"splits the catalog into {shards} shards")
+    if n % chunk or (n // chunk) % shards:
+        return (f"n_rows {n} is not a whole number of encode_chunk {chunk} "
+                f"chunks for each of {shards} shards")
+    return None
 
 
 def require_chip(chips: int, peaks: dict) -> dict:
@@ -248,9 +269,10 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     log("[window] " + panel_pauses(proxy.panels, window.t0) + "; "
         + collections.summary(window.t0, window.t1))
     server.close()
-    memory_peak = max(
-        (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-        for d in jax.local_devices())
+    peaks_in_use = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                    for d in jax.local_devices()]
+    memory_peak = max(peaks_in_use)
+    log(f"[memory] peak bytes in use by device: {peaks_in_use}")
     tr = trace_mod.load(str(TRACE_DIR)) if trace else None
 
     # ----------------------------------------------------------- metrics
@@ -318,6 +340,10 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     cell = Cell(args.workload)
+    fault = layout_fault(cell)
+    if fault:
+        log(f"bench: {args.workload}: {fault}")
+        return 2
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(BENCH))
     device = require_chip(int(cell.spec["chips"]),

@@ -79,18 +79,29 @@ class Catalog:
             jax.random.fold_in(self._key, 0), d=int(cfg["d"]),
             n_clusters=int(spec["n_clusters"]),
             decay=float(spec["spectrum_decay"]))
+        self._on_device: dict = {}
 
     @property
     def n_chunks(self) -> int:
         return self.n_rows // self.chunk
 
-    def rows(self, key, rows: int) -> jax.Array:
-        return _rows(key, *self.shape_arrays, rows=rows, noise=self.noise,
+    def rows(self, key, rows: int, device=None) -> jax.Array:
+        """``rows`` fresh rows from ``key``, made on ``device`` (the default
+        device where None)."""
+        arrays = self.shape_arrays
+        if device is not None:
+            if device not in self._on_device:
+                self._on_device[device] = jax.device_put(arrays, device)
+            arrays = self._on_device[device]
+            key = jax.device_put(key, device)
+        return _rows(key, *arrays, rows=rows, noise=self.noise,
                      zipf_a=self.zipf_a)
 
-    def chunk_rows(self, c: int) -> jax.Array:
-        """Rows ``[c * chunk, (c + 1) * chunk)`` of the catalog."""
-        return self.rows(jax.random.fold_in(self._key, c + 1), self.chunk)
+    def chunk_rows(self, c: int, device=None) -> jax.Array:
+        """Rows ``[c * chunk, (c + 1) * chunk)`` of the catalog, made on
+        ``device`` (the default device where None)."""
+        return self.rows(jax.random.fold_in(self._key, c + 1), self.chunk,
+                         device)
 
     def users(self, seed: int, n_users: int) -> np.ndarray:
         """User preference vectors, drawn like catalog rows (same

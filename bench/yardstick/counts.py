@@ -12,6 +12,17 @@ from __future__ import annotations
 F32 = 4
 
 
+def shards(cfg: dict) -> int:
+    """How many chips a configuration's catalog rows are split over (its
+    ``shards`` key, 1 where it has none)."""
+    return int(cfg.get("shards", 1))
+
+
+def rows_per_chip(cfg: dict) -> int:
+    """The index rows one chip holds and scans in each call."""
+    return int(cfg["n_rows"]) // shards(cfg)
+
+
 def code_bytes_per_row(fmt: str, k: int, h: int) -> int:
     """Stored bytes of one fixed-k code row in an index format:
     ``f32``: k float32 values + k int32 indices;
@@ -54,9 +65,11 @@ def least_seconds(flops: float, bytes_: float, peak: dict) -> float:
 
 def serve_least_seconds(cfg: dict, rows: int, top_n: int, peak: dict
                         ) -> float:
-    """The least time of one whole serve step of a ``rows``-row panel:
-    encoding it, then one read of the index to score and select it."""
-    d, h, k, n = (int(cfg[x]) for x in ("d", "h", "k", "n_rows"))
+    """The least time of one whole serve step of a ``rows``-row panel on
+    one chip: encoding it (every chip encodes the whole panel), then one
+    read of that chip's rows of the index to score and select it."""
+    d, h, k = (int(cfg[x]) for x in ("d", "h", "k"))
+    n = rows_per_chip(cfg)
     return (least_seconds(*encode_cost(rows, d, h, k), peak)
             + least_seconds(*scan_cost(cfg["index_format"], n, rows, k, h,
                                        top_n), peak))

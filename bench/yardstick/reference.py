@@ -48,7 +48,7 @@ import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
 EPS = 1e-8
-SUB = 4096  # catalog rows per reference step
+SUB = 4096  # catalog rows per reference block
 
 
 def _encode(w_enc, b_enc, x, k):
@@ -126,27 +126,32 @@ def _round_values(v, control: str | None, raw=None):
 
 
 class Reference:
-    """The reference index of one run: every catalog row's code, encoded
-    and stored by the reference, kept on the device in blocks."""
+    """The reference of one run.  It keeps nothing of the catalog: each pass
+    regenerates the rows from the seed, encodes and stores them block by
+    block (``SUB`` rows) and scores each block against the sample's query
+    codes as it is made, so a device holds one block of reference codes
+    whatever the catalog's size.  With ``shards`` > 1 in the configuration
+    each shard's rows (whole chunks, in order) run on a device of their
+    own, and the shards' tops are merged in shard order, which is the
+    order of ids."""
 
     def __init__(self, catalog, params: dict, cfg: dict, *, tie_rel: float):
         self.k, self.h = int(cfg["k"]), int(cfg["h"])
         self.fmt = cfg["index_format"]
         self.n = catalog.n_rows
         self.tie_rel = float(tie_rel)
+        self.catalog = catalog
         self.w_enc, self.b_enc = params["w_enc"], params["b_enc"]
-        sub = min(SUB, catalog.chunk)
-        blocks = []
-        for c in range(catalog.n_chunks):
-            x = catalog.chunk_rows(c)
-            for s in range(0, catalog.chunk, sub):
-                blocks.append(_catalog_codes(
-                    self.w_enc, self.b_enc, x[s:s + sub], k=self.k,
-                    fmt=self.fmt, tie_rel=self.tie_rel))
-            del x
-        # (blocks, sub, ...) stacks: one reference step per block
-        self.codes = [jnp.stack(part) for part in zip(*blocks)]
-        self.sub = sub
+        self.sub = min(SUB, catalog.chunk)
+        shards = int(cfg.get("shards", 1))
+        if catalog.n_chunks % shards:
+            raise ValueError(f"{catalog.n_chunks} chunks do not split into "
+                             f"{shards} shards")
+        if shards > len(jax.local_devices()):
+            raise ValueError(f"{shards} shards, {len(jax.local_devices())} "
+                             f"devices")
+        self.devices = ([None] if shards == 1
+                        else jax.local_devices()[:shards])
 
     # ------------------------------------------------------------ queries
     def query_codes(self, x: np.ndarray):
@@ -171,25 +176,105 @@ class Reference:
         (the reference), or, with ``control``, by the cosine of its own
         codes rounded to that precision.  Returns (values, ids), each
         (V, S, m)."""
-        return _scan(tuple(self.codes), qd, qn, m=m, control=control,
-                     h=self.h, sub=self.sub)
+        return self._pass(qd, qn, m, control, None)[0]
 
-    def bounds(self, qd, qn, ids: np.ndarray):
-        """(lo, hi), each (V, S, n), of the rows ``ids`` (S, n); ids outside
-        [0, N) get -inf."""
+    def top_and_bounds(self, qd, qn, m: int, ids: np.ndarray):
+        """``top(qd, qn, m)`` and, in the same pass, the (lo, hi), each
+        (V, S, n), of the rows ``ids`` (S, n); ids outside [0, N) get
+        -inf."""
         ids = np.asarray(ids)
         valid = (ids >= 0) & (ids < self.n)
-        lo, hi = _bounds_at(tuple(self.codes), qd, qn,
-                            jnp.asarray(np.where(valid, ids, 0)),
-                            sub=self.sub)
+        top, rows = self._pass(qd, qn, m, None, np.where(valid, ids, 0))
+        lo, hi = _bounds_at(rows, qd, qn)
         lo, hi = np.array(lo, np.float64), np.array(hi, np.float64)
         lo[:, ~valid] = -np.inf
         hi[:, ~valid] = -np.inf
-        return lo, hi
+        return top, lo, hi
+
+    def _pass(self, qd, qn, m, control, ids):
+        """One pass over the catalog: ((values, ids) of the top ``m``, and
+        with ``ids`` each served row's code entries, (S, n, ...) arrays).
+        Chunk ``c`` runs on the device of shard ``c // (n_chunks /
+        shards)``; at most two chunks a device are in flight."""
+        cat, sub = self.catalog, self.sub
+        per = cat.n_chunks // len(self.devices)
+        on = [_Shard(d, (self.w_enc, self.b_enc, qd, qn, ids), m, self.k)
+              for d in self.devices]
+        for j in range(per):
+            for s, sh in enumerate(on):
+                c = s * per + j
+                x = cat.chunk_rows(c, sh.device)
+                for off in range(0, cat.chunk, sub):
+                    valid = min(sub, cat.chunk - off)
+                    xb = x[off:off + sub]
+                    if valid < sub:   # the short last block, zero-padded
+                        xb = jnp.pad(xb, ((0, sub - valid), (0, 0)))
+                    blk = _catalog_codes(sh.w_enc, sh.b_enc, xb, k=self.k,
+                                         fmt=self.fmt, tie_rel=self.tie_rel)
+                    b0 = c * cat.chunk + off
+                    sh.top = _top_step(sh.top, blk, b0, valid, sh.qd, sh.qn,
+                                       m=m, control=control, h=self.h)
+                    if ids is not None:
+                        sh.rows = _gather_step(sh.rows, blk, b0, valid,
+                                               sh.ids)
+                del x
+            if j:
+                jax.block_until_ready([sh.done for sh in on])
+            for sh in on:
+                sh.done = sh.top
+        if len(on) == 1:
+            return on[0].top, on[0].rows
+        # shard order is the order of ids: ties go to the lowest id, as in
+        # one pass over every block in turn
+        home = on[0].device
+        vals, allid = (jnp.concatenate(
+            [jax.device_put(sh.top[i], home) for sh in on], axis=-1)
+            for i in (0, 1))
+        top_v, pos = jax.lax.top_k(vals, m)
+        top = (top_v, jnp.take_along_axis(allid, pos, axis=-1))
+        if ids is None:
+            return top, None
+        owner = jnp.asarray(ids // (self.n // len(on)), jnp.int32)
+        rows = on[0].rows
+        for s, sh in enumerate(on[1:], 1):
+            mine = owner == s
+            rows = tuple(
+                jnp.where(mine.reshape(mine.shape + (1,) * (a.ndim - 2)),
+                          jax.device_put(b, home), a)
+                for a, b in zip(rows, sh.rows))
+        return top, rows
 
 
-@functools.partial(jax.jit, static_argnames=("m", "control", "h", "sub"))
-def _scan(codes, qd, qn, *, m, control, h, sub):
+class _Shard:
+    """One device's part of a pass: its copies of the weights, the query
+    codes and the served ids, its running top ``m`` and the code entries of
+    the served rows it has found."""
+
+    def __init__(self, device, arrays, m: int, k: int):
+        w_enc, b_enc, qd, qn, ids = arrays
+        v_, s_, _ = qd.shape
+        top = (jnp.full((v_, s_, m), -jnp.inf), jnp.full((v_, s_, m), -1))
+        rows = None
+        if ids is not None:
+            ids = jnp.asarray(ids, jnp.int32)
+            f32, i32 = jnp.float32, jnp.int32
+            rows = tuple(jnp.zeros(ids.shape + tail, t) for tail, t in (
+                ((k,), f32), ((k,), i32), ((k + 1,), f32), ((k + 1,), i32),
+                ((), f32), ((), f32)))
+        arrays = (w_enc, b_enc, qd, qn, ids, top, rows)
+        if device is not None:
+            arrays = jax.device_put(arrays, device)
+        (self.w_enc, self.b_enc, self.qd, self.qn, self.ids, self.top,
+         self.rows) = arrays
+        self.device = device
+        self.done = None
+
+
+@functools.partial(jax.jit, static_argnames=("m", "control", "h"))
+def _top_step(carry, blk, b0, valid, qd, qn, *, m, control, h):
+    """The running top ``m`` of each (variant, query) after one block of
+    ``valid`` rows starting at row ``b0`` (rows past ``valid`` are padding
+    and never enter)."""
     v_, s_, _ = qd.shape
     qflat = qd.reshape(v_ * s_, h)
     qnf = qn.reshape(v_ * s_, 1)
@@ -197,36 +282,45 @@ def _scan(codes, qd, qn, *, m, control, h, sub):
         qflat = _round_values(qflat, control)
         qnf = jnp.maximum(jnp.sqrt(jnp.sum(qflat * qflat, axis=-1,
                                            keepdims=True)), EPS)
-    init = (jnp.full((v_ * s_, m), -jnp.inf), jnp.full((v_ * s_, m), -1))
-
-    def step(carry, blk):
-        b, (stored, raw, cidx, u_val, u_idx, sq, d_sq) = blk
-        if control is None:
-            dot = jnp.matmul(qflat, _dense(stored, cidx, h).T, precision=HI)
-            rad = jnp.matmul(jnp.abs(qflat), _dense(u_val, u_idx, h).T,
-                             precision=HI)
-            score, _ = _bounds(dot, rad, sq[None], d_sq[None], qnf)
-        else:
-            cv = _round_values(stored, control, raw)
-            cn = jnp.maximum(jnp.sqrt(jnp.sum(cv * cv, axis=-1)), EPS)
-            score = jnp.matmul(qflat, _dense(cv, cidx, h).T,
-                               precision=HI) / (cn[None] * qnf)
-        ids = b * sub + jnp.arange(score.shape[1])
-        vals = jnp.concatenate([carry[0], score], axis=1)
-        allid = jnp.concatenate(
-            [carry[1], jnp.broadcast_to(ids, score.shape)], axis=1)
-        top_v, pos = jax.lax.top_k(vals, m)
-        return (top_v, jnp.take_along_axis(allid, pos, axis=1)), None
-
-    nblk = codes[0].shape[0]
-    (top_v, top_i), _ = jax.lax.scan(step, init, (jnp.arange(nblk), codes))
+    carry = (carry[0].reshape(v_ * s_, m), carry[1].reshape(v_ * s_, m))
+    stored, raw, cidx, u_val, u_idx, sq, d_sq = blk
+    if control is None:
+        dot = jnp.matmul(qflat, _dense(stored, cidx, h).T, precision=HI)
+        rad = jnp.matmul(jnp.abs(qflat), _dense(u_val, u_idx, h).T,
+                         precision=HI)
+        score, _ = _bounds(dot, rad, sq[None], d_sq[None], qnf)
+    else:
+        cv = _round_values(stored, control, raw)
+        cn = jnp.maximum(jnp.sqrt(jnp.sum(cv * cv, axis=-1)), EPS)
+        score = jnp.matmul(qflat, _dense(cv, cidx, h).T,
+                           precision=HI) / (cn[None] * qnf)
+    real = jnp.arange(score.shape[1]) < valid
+    score = jnp.where(real, score, -jnp.inf)
+    ids = jnp.where(real, b0 + jnp.arange(score.shape[1]), -1)
+    vals = jnp.concatenate([carry[0], score], axis=1)
+    allid = jnp.concatenate(
+        [carry[1], jnp.broadcast_to(ids, score.shape)], axis=1)
+    top_v, pos = jax.lax.top_k(vals, m)
+    top_i = jnp.take_along_axis(allid, pos, axis=1)
     return top_v.reshape(v_, s_, m), top_i.reshape(v_, s_, m)
 
 
-@functools.partial(jax.jit, static_argnames=("sub",))
-def _bounds_at(codes, qd, qn, ids, *, sub):
-    blk, row = ids // sub, ids % sub
-    stored, _, cidx, u_val, u_idx, sq, d_sq = (a[blk, row] for a in codes)
+@jax.jit
+def _gather_step(rows, blk, b0, valid, ids):
+    """``rows`` (each (S, n, ...)) with the code entries of the served ids
+    that lie in the block's ``valid`` rows from row ``b0`` filled in."""
+    local = ids - b0
+    here = (local >= 0) & (local < valid)
+    at = jnp.where(here, local, 0)
+    stored, _, cidx, u_val, u_idx, sq, d_sq = blk
+    found = tuple(a[at] for a in (stored, cidx, u_val, u_idx, sq, d_sq))
+    return tuple(jnp.where(here.reshape(here.shape + (1,) * (a.ndim - 2)),
+                           a, r) for a, r in zip(found, rows))
+
+
+@jax.jit
+def _bounds_at(rows, qd, qn):
+    stored, cidx, u_val, u_idx, sq, d_sq = rows
     # each query's dense code read at its served rows' latents
     at = jax.vmap(lambda q, i: q[i])                   # over requests
     q_at = jax.vmap(at, (0, None))(qd, cidx)          # (V, S, n, k)
@@ -241,8 +335,8 @@ def compare(ref: Reference, queries: np.ndarray, ids: np.ndarray,
     """Per-request and worst ``score_gap`` / ``rank_gap`` of served answers
     (ids, scores: (S, n)) to the requests' dense queries (S, d)."""
     qd, qn, qtie = ref.query_codes(queries)
-    lo, hi = ref.bounds(qd, qn, ids)                   # (V, S, n)
-    top_v, top_i = (np.asarray(a) for a in ref.top(qd, qn, top_n + 1))
+    (top_v, top_i), lo, hi = ref.top_and_bounds(qd, qn, top_n + 1, ids)
+    top_v, top_i = np.asarray(top_v), np.asarray(top_i)   # lo, hi (V, S, n)
     scores = np.asarray(scores, np.float64)
     below = np.maximum(lo - scores[None], 0.0)
     above = np.maximum(scores[None] - hi, 0.0)

@@ -10,8 +10,9 @@ from yardstick import counts
 def kernel_roofline(run, pattern: str, cost) -> float | None:
     """A kernel's share of its roofline, in %: over the window's panels,
     the least time the peaks allow for each call of the kernel whose ops
-    match ``pattern`` (``cost(rows) -> (flops, bytes)``), over the device
-    time of those calls."""
+    match ``pattern`` (``cost(rows) -> (flops, bytes)``, one chip's call),
+    over the device time of those calls.  On several chips each chip's call
+    is an op of its own, charged for what that chip does."""
     tr = run.trace
     least = took = 0.0
     for span in tr.spans_named("panel"):
@@ -24,8 +25,9 @@ def kernel_roofline(run, pattern: str, cost) -> float | None:
 
 def serve_mfu(run) -> float | None:
     """The whole serve step's share of the chip's peaks, in %: the sum over
-    the window's panels of the step's least time (encoding the panel and
-    one read of the index to score and select it), over the window."""
+    the window's panels of the step's least time on one chip (encoding the
+    panel and one read of that chip's rows of the index to score and select
+    it), over the window."""
     tr = run.trace
     spans = tr.spans_named("panel")
     if not spans or not tr.ops:
